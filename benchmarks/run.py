@@ -37,6 +37,7 @@ from benchmarks import (
     stream,
 )
 from benchmarks.common import bench_meta
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import log as obs_log
 
 SUITES = {
@@ -90,6 +91,7 @@ def main() -> None:
     obs_log.add_verbosity_args(ap)
     args = ap.parse_args()
     obs_log.configure(obs_log.verbosity_from(args))
+    use_compile_cache()
     names = args.only.split(",") if args.only else list(SUITES)
     print("suite,us_per_call,derived")
     failures = 0

@@ -158,22 +158,16 @@ def constrain(x, *logical_spec):
     these constraints the 0.5B-vocab CE graph all-gathered the whole global
     batch per device (EXPERIMENTS.md §Perf, iteration 0).
     """
-    # jax >= 0.5 exposes the ambient abstract mesh; older versions only have
-    # the legacy thread-resources context, handled by the fallback below.
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh = get_abstract_mesh() if get_abstract_mesh is not None else None
-    if mesh is None or not mesh.axis_names:
-        # fall back to the legacy `with mesh:` context (what pjit resolves
-        # bare PartitionSpecs against).
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
+        # `with mesh:` (launch/dryrun.py) sets only the thread-resources
+        # context, not the abstract mesh; bare PartitionSpecs resolve there.
         from jax._src import mesh as mesh_lib
 
         mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh is None or mesh.empty:
+        if mesh.empty:
             return x
-    if hasattr(mesh, "axis_sizes"):
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-    else:
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     spec = []
     for dim, ax in zip(x.shape, logical_spec):
         if ax is None:

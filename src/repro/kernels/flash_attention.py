@@ -30,9 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_kernel", "flash_attention"]
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -129,7 +126,7 @@ def flash_attention_kernel(
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(qf, kf, vf)

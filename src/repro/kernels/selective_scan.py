@@ -21,14 +21,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["selective_scan_kernel"]
+
+#: timesteps per aligned row tile; block_s is rounded to a multiple of it.
+_ROWS = 16
 
 
 def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
-            h_ref, *, block_s: int, seq_len: int):
+            h_ref, *, block_s: int):
     ib = pl.program_id(2)
     ns = pl.num_programs(2)
 
@@ -37,20 +37,26 @@ def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     a = a_ref[...].astype(jnp.float32)          # [bd, N]
-    d_skip = d_ref[...].astype(jnp.float32)     # [bd]
+    d_skip = d_ref[0].astype(jnp.float32)       # [bd]
 
-    def step(t, h):
-        dt = dt_ref[0, t].astype(jnp.float32)   # [bd]
-        u = u_ref[0, t].astype(jnp.float32)     # [bd]
-        bt = b_ref[0, t].astype(jnp.float32)    # [N]
-        ct = c_ref[0, t].astype(jnp.float32)    # [N]
-        decay = jnp.exp(dt[:, None] * a)        # [bd, N]
-        h = decay * h + (dt * u)[:, None] * bt[None, :]
-        y = (h * ct[None, :]).sum(axis=1) + d_skip * u
-        y_ref[0, t] = y.astype(y_ref.dtype)
+    # Mosaic loads only sublane-aligned row tiles (16 rows covers bf16's
+    # packed (16, 128) tiling), so each tile is loaded once and its
+    # timesteps are indexed in registers.
+    def tile(r, h):
+        t0 = pl.multiple_of(r * _ROWS, _ROWS)
+        rows = pl.ds(t0, _ROWS)
+        u = u_ref[0, rows, :].astype(jnp.float32)    # [rows, bd]
+        dt = dt_ref[0, rows, :].astype(jnp.float32)  # [rows, bd]
+        bt = b_ref[0, rows, :].astype(jnp.float32)   # [rows, N]
+        ct = c_ref[0, rows, :].astype(jnp.float32)   # [rows, N]
+        for i in range(_ROWS):
+            decay = jnp.exp(dt[i][:, None] * a)      # [bd, N]
+            h = decay * h + (dt[i] * u[i])[:, None] * bt[i][None, :]
+            y = (h * ct[i][None, :]).sum(axis=1) + d_skip * u[i]
+            y_ref[0, t0 + i] = y.astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, h_ref[...])
+    h = jax.lax.fori_loop(0, block_s // _ROWS, tile, h_ref[...])
     h_ref[...] = h
 
     @pl.when(ib == ns - 1)
@@ -69,8 +75,9 @@ def selective_scan_kernel(
     bsz, s, di = u.shape
     n = a.shape[1]
     block_d = min(block_d, di)
-    block_s = min(block_s, s)
+    block_s = min(block_s, pl.cdiv(s, _ROWS) * _ROWS)
     assert di % block_d == 0, (di, block_d)
+    assert block_s % _ROWS == 0, (block_s, _ROWS)
     pad_s = (-s) % block_s
     if pad_s:
         z = ((0, 0), (0, pad_s), (0, 0))
@@ -79,7 +86,7 @@ def selective_scan_kernel(
     sp = s + pad_s
     grid = (bsz, di // block_d, sp // block_s)
 
-    kernel = functools.partial(_kernel, block_s=block_s, seq_len=s)
+    kernel = functools.partial(_kernel, block_s=block_s)
     y, h_last = pl.pallas_call(
         kernel,
         grid=grid,
@@ -89,7 +96,8 @@ def selective_scan_kernel(
             pl.BlockSpec((block_d, n), lambda ib_, id_, is_: (id_, 0)),
             pl.BlockSpec((1, block_s, n), lambda ib_, id_, is_: (ib_, is_, 0)),
             pl.BlockSpec((1, block_s, n), lambda ib_, id_, is_: (ib_, is_, 0)),
-            pl.BlockSpec((block_d,), lambda ib_, id_, is_: (id_,)),
+            # 2-D so the block's layout matches XLA's tiling of the operand.
+            pl.BlockSpec((1, block_d), lambda ib_, id_, is_: (0, id_)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_s, block_d), lambda ib_, id_, is_: (ib_, is_, id_)),
@@ -101,9 +109,9 @@ def selective_scan_kernel(
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-    )(u, dt, a, b_ssm, c_ssm, d_skip)
+    )(u, dt, a, b_ssm, c_ssm, d_skip.reshape(1, di))
     # dt=0 padding leaves h untouched (decay=1, input=0), so h_last is exact.
     return y[:, :s], h_last

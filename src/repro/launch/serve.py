@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import encdec, lm
 from repro.serve.engine import ServeEngine
 
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--first-id", type=int, default=0,
                     help="first sample id to read from the tier")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

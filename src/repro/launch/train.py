@@ -21,8 +21,9 @@
     PYTHONPATH=src python -m repro.launch.train stream --distributed \
         --nodes 2 --backend sharded --num-samples 2048 --verify
 
-Runs on whatever devices are visible (CPU here; the same code path drives
-the production mesh — the dry-run proves the sharded lowering).
+Trains on the default JAX device — one TPU chip, or the CPU under
+``JAX_PLATFORMS=cpu`` — with no mesh: nothing here shards across chips.
+``chip_smoke.py`` at the repository root is the on-chip check of this path.
 """
 from __future__ import annotations
 
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import log as obs_log
 from repro.data import (
     STRATEGIES,
@@ -556,6 +558,7 @@ def main(argv=None):
              "window plans, deterministic vs an offline replan"))
     args = ap.parse_args(argv)
     obs_log.configure(obs_log.verbosity_from(args))
+    use_compile_cache()
     if args.cmd == "plan":
         run_plan(args)
     elif args.cmd == "distributed":

@@ -1,5 +1,6 @@
 """Per-kernel allclose vs the pure-jnp oracle, swept over shapes/dtypes
-(interpret mode executes the exact kernel body + BlockSpec tiling)."""
+(interpret mode executes the exact kernel body + BlockSpec tiling; the
+chip's compiler is exercised by tests/test_tpu_compile.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +31,7 @@ def test_flash_attention_vs_oracle(b, h, kh, sq, sk, hd, causal, window, dtype):
     k = _rand((b, kh, sk, hd), dtype, 1)
     v = _rand((b, kh, sk, hd), dtype, 2)
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=32, block_k=32)
+                              block_q=32, block_k=32, interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -55,7 +56,8 @@ def test_selective_scan_vs_oracle(b, s, di, n, bd, bs, dtype):
     bssm = _rand((b, s, n), dtype, 3)
     cssm = _rand((b, s, n), dtype, 4)
     d = jnp.ones((di,), jnp.float32)
-    y, h = ops.selective_scan(u, dt, a, bssm, cssm, d, block_d=bd, block_s=bs)
+    y, h = ops.selective_scan(u, dt, a, bssm, cssm, d, block_d=bd, block_s=bs,
+                              interpret=True)
     yr, hr = ref.selective_scan_ref(u, dt, a, bssm, cssm, d)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=tol, rtol=tol)
@@ -67,7 +69,7 @@ def test_selective_scan_vs_oracle(b, s, di, n, bd, bs, dtype):
 def test_rms_norm_vs_oracle(rows, d, block, dtype):
     x = _rand((rows, d), dtype, 0)
     s = _rand((d,), jnp.float32, 1) * 0.1
-    out = ops.rms_norm(x, s, block_rows=block)
+    out = ops.rms_norm(x, s, block_rows=block, interpret=True)
     want = ref.rms_norm_ref(x, s)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -85,6 +87,6 @@ def test_model_attention_matches_kernel_path():
     v = _rand((1, 2, 128, 32), jnp.float32, 2)
     a_ref = L.attention(q, k, v, impl="ref")
     a_blk = L.attention(q, k, v, impl="blockwise")
-    a_pal = ops.flash_attention(q, k, v, block_q=64, block_k=64)
+    a_pal = ops.flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
     np.testing.assert_allclose(np.asarray(a_ref), np.asarray(a_blk), atol=2e-5)
     np.testing.assert_allclose(np.asarray(a_ref), np.asarray(a_pal), atol=2e-5)

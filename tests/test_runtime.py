@@ -496,7 +496,9 @@ def test_launcher_restarted_rank_rejoins_and_reclaims_its_slice(tmp_path):
     death, adoption, and handback."""
     from repro.runtime import in_process_aggregate
 
-    spec = _dist_spec(tmp_path, 4, epochs=2)
+    # 4 epochs (64 steps): the respawned process must re-register before
+    # the survivors finish; under a loaded host that takes ~30 steps.
+    spec = _dist_spec(tmp_path, 4, epochs=4)
     report = run_distributed(
         spec, timeout_s=240.0, die_at_step={1: 3}, restart_ranks={1},
     )

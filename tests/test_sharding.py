@@ -93,3 +93,27 @@ def test_choose_spec_divisibility():
     # dim 100 not divisible by 16: axis dropped
     sp = S.choose_spec((100, 64), [("data", "model")], SINGLE)
     assert sp == P(None, "model")
+
+
+@pytest.mark.parametrize("context", ["none", "with_mesh", "set_mesh"])
+def test_constrain_resolves_the_ambient_mesh(context):
+    """``constrain`` pins activations under both mesh contexts: the
+    abstract mesh of ``jax.sharding.set_mesh`` and the thread-resources
+    context of ``with mesh:`` (which launch/dryrun.py uses); outside any
+    mesh it is a no-op."""
+    import contextlib
+
+    import jax
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    ctx = {
+        "none": contextlib.nullcontext,
+        "with_mesh": lambda: mesh,
+        "set_mesh": lambda: jax.sharding.set_mesh(mesh),
+    }[context]
+    with ctx():
+        text = jax.jit(lambda x: S.constrain(x, "data", None) * 2).lower(
+            np.ones((4, 4), np.float32)
+        ).as_text()
+    assert ("sharding_constraint" in text) == (context != "none")

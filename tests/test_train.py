@@ -62,7 +62,7 @@ def test_node_sample_remap_invariance():
                                    lambda p, b: lm.train_loss(p, b, CFG)))
     s1, _ = step(init_train_state(params, OPT), batch)
     s2, _ = step(init_train_state(params, OPT), shuffled)
-    # float32 reduction order differs under permutation: on jax 0.4.37/CPU
+    # float32 reduction order differs under permutation: on the CPU backend
     # the XLA sum ordering yields ~1.6e-6 max delta for a bit-invariant
     # update, so 1e-6 was unattainable.  5e-6 still bounds the divergence to
     # reassociation noise (weights are O(1e-1), lr 1e-3); a genuine remap
