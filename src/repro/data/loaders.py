@@ -40,6 +40,7 @@ import numpy as np
 from repro.core.costmodel import PeerCostModel, PFSCostModel
 from repro.core.plan import Schedule
 from repro.data.backends.base import StorageBackend
+from repro.obs import trace as obs_trace
 
 __all__ = [
     "StepBatch",
@@ -69,6 +70,8 @@ class StepBatch:
         gradients identical to the unpadded batch (DESIGN.md §3).
         """
         assert self.node_data is not None
+        tr = obs_trace.get()
+        t0 = tr.t()
         n = len(self.node_ids)
         shape = self.node_data[0].shape[1:]
         dtype = self.node_data[0].dtype
@@ -78,6 +81,7 @@ class StepBatch:
             k = min(arr.shape[0], capacity)
             data[i, :k] = arr[:k]
             weights[i, :k] = 1.0
+        tr.rec(obs_trace.BATCH_TO_GLOBAL, t0, a=n * capacity, b=data.nbytes)
         return data.reshape((n * capacity,) + shape), weights.reshape(-1)
 
 

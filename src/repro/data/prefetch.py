@@ -255,6 +255,7 @@ class PrefetchExecutor:
                     pass
 
     def _produce_schedule(self, run: _Run) -> None:
+        tr = obs_trace.get()
         ld = self.loader
         collect = ld.collect_data
         gather_peers = getattr(ld, "gather_peers", None)
@@ -298,7 +299,11 @@ class PrefetchExecutor:
             # are applied, this step's are not — and they overlap the tail of
             # this step's in-flight chunk reads.
             peer_arrays = gather_peers(sp) if gather_peers is not None else None
-            chunk_arrays = [f.result() for f in futs] if futs else None
+            chunk_arrays = None
+            if futs:
+                t0 = tr.t()
+                chunk_arrays = [f.result() for f in futs]
+                tr.rec(obs_trace.PREFETCH_READ_WAIT, t0, a=len(futs))
             if gather_peers is not None:
                 sb = ld.execute_step(
                     ep, sp, chunk_arrays=chunk_arrays, peer_arrays=peer_arrays

@@ -5,6 +5,12 @@
         --reduced --steps 50 --loader solar --backend sharded \
         --data /tmp/tokens.bin --plan-cache /tmp/solar_plans
 
+    # the same, traced: program spans + JAX profiler trace, then the report
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
+        --reduced --steps 50 --trace-dir /tmp/solar_trace
+    PYTHONPATH=src python -m repro.obs.report /tmp/solar_trace \
+        --xplane /tmp/solar_trace
+
     # precompute / inspect plan artifacts without training
     PYTHONPATH=src python -m repro.launch.train plan --loader solar \
         --num-samples 32768 --nodes 8 --local-batch 32 --buffer 3072 \
@@ -37,6 +43,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.obs import log as obs_log
+from repro.obs import trace as obs_trace
 from repro.data import (
     STRATEGIES,
     DatasetSpec,
@@ -93,6 +100,10 @@ def _add_train_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--checkpoint-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
+    ap.add_argument("--trace-dir", default=None, metavar="DIR",
+                    help="record the program's spans and a JAX profiler "
+                         "trace into DIR (DESIGN.md §13); analyze with "
+                         "`python -m repro.obs.report DIR --xplane DIR`")
 
 
 def _add_plan_args(ap: argparse.ArgumentParser) -> None:
@@ -466,6 +477,8 @@ def run_stream_cmd(args) -> None:
 
 
 def run_train(args) -> None:
+    if args.trace_dir:
+        obs_trace.enable()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -528,7 +541,16 @@ def run_train(args) -> None:
         checkpoint_every=args.checkpoint_every, skip_steps=skip,
         prefetch_depth=args.prefetch_depth, num_workers=args.num_workers,
     )
-    trainer.run(max_steps=args.steps)
+    if args.trace_dir:
+        jax.profiler.start_trace(args.trace_dir)
+    try:
+        trainer.run(max_steps=args.steps)
+    finally:
+        if args.trace_dir:
+            jax.profiler.stop_trace()
+            dump = obs_trace.disable().dump(args.trace_dir, rank=0)
+            print(f"trace: {dump['records']} spans ({dump['dropped']} "
+                  f"dropped) and the profiler's xplane in {args.trace_dir}")
     for rec in trainer.metrics_history[:: max(len(trainer.metrics_history) // 10, 1)]:
         print(f"step {rec['step']:5d} loss {rec['loss']:.4f}")
     print(json.dumps(trainer.breakdown(), indent=1))

@@ -8,14 +8,19 @@ nothing else — a tracing-off run is byte-identical to an uninstrumented one.
 Records are **complete spans**: one fixed-dtype numpy row per span with
 begin/end timestamps from ``time.perf_counter()`` (the per-process monotonic
 clock — timestamps compare within one rank process, never across ranks).
+A traced ``Trainer.run`` also enters a JAX profiler step per ``train.step``
+span, right after reading its ``t0``: each step is an anchor that maps
+these timestamps onto the device trace's clock (``repro.obs.report
+--xplane``).
 Every thread appends into its own preallocated ring buffer, so recording is
 lock-free and allocation-free: a full ring wraps and overwrites the oldest
 rows (the count of overwritten rows is reported as ``dropped``).
 
 Span *kinds* are interned strings; the well-known kinds below cover the
-whole data-loading runtime (chunk reads, prefetch queue waits, peer
-fetch/retry/breaker, buffer-server serve/skew-park/tenant-yield, barrier
-waits, rank-loop step sections, trainer compute, fault firings).  Sites
+whole data-loading runtime (chunk reads, prefetch queue and read waits,
+batch assembly, peer fetch/retry/breaker, buffer-server
+serve/skew-park/tenant-yield, barrier waits, rank-loop step sections,
+``Trainer.run``'s step sections, fault firings).  Sites
 stamp two free integer payload fields ``a``/``b`` (bytes read, source node,
 attempt index, ...) and the tracer's *current step* — set by the rank loop
 via :meth:`Tracer.set_step` — so the report CLI can attribute every span,
@@ -95,6 +100,11 @@ HB_SEND = kind_id("hb.send")                    # synchronous heartbeat
 TRAIN_MAKE_BATCH = kind_id("train.make_batch")  # StepBatch -> model batch
 TRAIN_COMPUTE = kind_id("train.compute")        # jitted step + block_until_ready
 FAULT = kind_id("fault")                        # instant; a=nth/step, b=seed
+TRAIN_STEP = kind_id("train.step")              # one Trainer.run iteration
+TRAIN_METRICS = kind_id("train.metrics")        # metrics -> Python floats
+TRAIN_CHECKPOINT = kind_id("train.checkpoint")  # Trainer's checkpoint save
+BATCH_TO_GLOBAL = kind_id("batch.to_global")    # a=rows, b=bytes returned
+PREFETCH_READ_WAIT = kind_id("prefetch.read_wait")  # a=read tasks waited on
 
 _NULL_CTX = nullcontext()
 

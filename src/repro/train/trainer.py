@@ -15,10 +15,12 @@ built around SOLAR's contract:
     skipped steps' buffer deltas via ``ScheduleExecutor.fast_forward`` —
     zero I/O instead of re-reading every skipped batch,
   * per-step wall times are tracked separately for load vs compute — the
-    paper's Fig. 3 breakdown comes straight from these counters.
+    paper's Fig. 3 breakdown comes straight from these counters, read off
+    the same clock reads as the ``train.*`` spans that tile each step.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 
 import jax
@@ -38,6 +40,8 @@ from repro.data.pipeline import LoaderSpec, build_pipeline
 from repro.data.prefetch import PrefetchExecutor
 
 __all__ = ["Trainer"]
+
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 class Trainer:
@@ -126,39 +130,57 @@ class Trainer:
             fast_forward(self.skip_steps)
             global_step = self.skip_steps
         tr = obs_trace.get()
+        # Traced, each iteration is also a profiler step, entered right after
+        # reading train.step's t0: the pair anchors the recorder's clock to
+        # the device trace's (DESIGN.md §13).  Untraced, nothing is entered.
+        annotate = jax.profiler.StepTraceAnnotation if tr.enabled else None
+        batches = iter(source)
         try:
-            for sb in source:
-                if global_step < self.skip_steps:
-                    global_step += 1
-                    continue
+            # loaders without a plan skip by iteration
+            while global_step < self.skip_steps and next(batches, None) is not None:
+                global_step += 1
+            while True:
                 tr.set_step(global_step)
                 t0 = time.perf_counter()
-                batch = self.make_batch(sb)
-                t1 = time.perf_counter()
-                tr.rec(obs_trace.TRAIN_MAKE_BATCH, t0, t1)
-                self.state, metrics = self.step_fn(self.state, batch)
-                jax.block_until_ready(metrics["loss"])
-                t2 = time.perf_counter()
-                tr.rec(obs_trace.TRAIN_COMPUTE, t1, t2)
-                self.load_time_s += t1 - t0
-                self.compute_time_s += t2 - t1
-                rec = {k: float(np.asarray(v)) for k, v in metrics.items()}
-                rec["step"] = global_step
-                self.metrics_history.append(rec)
-                global_step += 1
-                if (
-                    self.ckpt
-                    and self.checkpoint_every
-                    and global_step % self.checkpoint_every == 0
-                ):
-                    self.ckpt.save(
-                        global_step,
-                        self.state,
-                        extra=plan_cursor_extra(
-                            global_step, sb.epoch, sb.step,
-                            plan_hash=getattr(self.loader, "config_hash", None),
-                        ),
-                    )
+                with (annotate("train", step_num=global_step) if annotate
+                      else _NO_ANNOTATION):
+                    sb = next(batches, None)
+                    if sb is None:
+                        break
+                    t1 = time.perf_counter()
+                    batch = self.make_batch(sb)
+                    t2 = time.perf_counter()
+                    tr.rec(obs_trace.TRAIN_MAKE_BATCH, t1, t2)
+                    self.state, metrics = self.step_fn(self.state, batch)
+                    jax.block_until_ready(metrics["loss"])
+                    t3 = time.perf_counter()
+                    tr.rec(obs_trace.TRAIN_COMPUTE, t2, t3)
+                    rec = {k: float(np.asarray(v)) for k, v in metrics.items()}
+                    t4 = time.perf_counter()
+                    tr.rec(obs_trace.TRAIN_METRICS, t3, t4)
+                    rec["step"] = global_step
+                    self.metrics_history.append(rec)
+                    global_step += 1
+                    t5 = t4
+                    if (
+                        self.ckpt
+                        and self.checkpoint_every
+                        and global_step % self.checkpoint_every == 0
+                    ):
+                        self.ckpt.save(
+                            global_step,
+                            self.state,
+                            extra=plan_cursor_extra(
+                                global_step, sb.epoch, sb.step,
+                                plan_hash=getattr(self.loader, "config_hash", None),
+                            ),
+                        )
+                        t5 = time.perf_counter()
+                        tr.rec(obs_trace.TRAIN_CHECKPOINT, t4, t5)
+                    tr.rec(obs_trace.TRAIN_STEP, t0, t5)
+                # the wait for the batch and its assembly; the device step
+                self.load_time_s += t2 - t0
+                self.compute_time_s += t3 - t2
                 if max_steps is not None and global_step >= max_steps:
                     break
         finally:
@@ -169,8 +191,11 @@ class Trainer:
         return self.state
 
     def breakdown(self) -> dict:
-        """Paper Fig. 3-style time split (loader wall time includes PFS reads
-        performed on the prefetch thread, which overlap compute)."""
+        """Paper Fig. 3-style time split from the clock reads of the
+        ``train.*`` spans: ``load_s`` is the wait for each next batch plus
+        ``make_batch`` (reads done on the prefetch thread count only where
+        the loop waited on them), ``compute_s`` the step through its
+        ``block_until_ready``."""
         total = self.load_time_s + self.compute_time_s
         return {
             "load_s": round(self.load_time_s, 4),

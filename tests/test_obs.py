@@ -359,6 +359,223 @@ def test_report_main_check_cli(tmp_path, capsys):
     assert rc == 1
 
 
+def _steps_dump(tmp_path, steps=3):
+    """A ``Trainer.run`` trace: ``train.step`` spans, ms apart, each tiled by
+    qwait / make_batch (with ``batch.to_global``) / compute / metrics."""
+    tr = Tracer(capacity=256)
+    for s in range(steps):
+        tr.set_step(s)
+        t = 1.0 + 0.010 * s
+        tr.rec(obs_trace.PREFETCH_QWAIT, t, t + 0.001)
+        tr.rec(obs_trace.BATCH_TO_GLOBAL, t + 0.0015, t + 0.002, a=8, b=64)
+        tr.rec(obs_trace.TRAIN_MAKE_BATCH, t + 0.001, t + 0.003)
+        tr.rec(obs_trace.TRAIN_COMPUTE, t + 0.003, t + 0.007)
+        tr.rec(obs_trace.TRAIN_METRICS, t + 0.007, t + 0.008)
+        tr.rec(obs_trace.TRAIN_STEP, t, t + 0.009)
+    tr.rec(obs_trace.CHUNK_READ, 1.0, 1.001, a=8)
+    return tr.records()[0], tr.dump(str(tmp_path), rank=0)
+
+
+def test_report_tiles_trainer_loop(tmp_path):
+    _steps_dump(tmp_path)
+    rep = obs_report.analyze(str(tmp_path))
+    t = rep["ranks"]["0"]["train"]
+    assert t["steps"] == 3
+    assert t["step_ms_mean"] == pytest.approx(9.0, abs=0.01)
+    assert t["stage_ms_per_step"]["compute"] == pytest.approx(4.0, abs=0.01)
+    assert t["coverage"] == pytest.approx(8.0 / 9.0, abs=0.001)
+    # a Trainer-only trace is checked against its own loop's coverage
+    assert obs_report.check(str(tmp_path), min_coverage=0.85) == []
+    fails = obs_report.check(str(tmp_path), min_coverage=0.95)
+    assert any("train.step coverage" in f for f in fails)
+
+
+def _xplane(steps, offset_ns, ops):
+    """A profiler trace as :func:`repro.obs.report.load_xplane` returns it."""
+    return {"path": "synthetic", "steps": steps,
+            "devices": {"/device:TPU:0": ops} if ops else {}}
+
+
+def test_clock_split_pairs_steps_and_splits_idle(tmp_path):
+    recs, _ = _steps_dump(tmp_path)
+    records = [
+        {"name": obs_trace.kind_name(int(r["kind"])), "ts": float(r["t0"]),
+         "dur": float(r["t1"] - r["t0"]), "step": int(r["step"]), "tid": "main"}
+        for r in recs
+    ]
+    off = 5_000_000   # profiler ns = perf_counter ns + off (+ jitter)
+    jitter = [0, 3_000, -1_000]
+    steps = {s: (round((1.0 + 0.010 * s) * 1e9) + off + jitter[s],
+                 round((1.009 + 0.010 * s) * 1e9) + off + jitter[s])
+             for s in range(3)}
+    steps[99] = (0, 1)  # a profiler step with no span: not paired
+    # the device is busy exactly during each step's compute section
+    ops = [(round((1.003 + 0.010 * s) * 1e9) + off,
+            round((1.007 + 0.010 * s) * 1e9) + off) for s in range(3)]
+    out = obs_report.clock_split(records, _xplane(steps, off, ops))
+    assert out["pairs"] == 3
+    assert out["offset_ns"] == pytest.approx(off, abs=1)
+    assert out["spread_ns"] == pytest.approx(4_000, abs=2)
+    assert out["window_s"] == pytest.approx(0.027, abs=1e-6)
+    assert out["busy_s"] == pytest.approx(0.012, abs=1e-5)
+    idle = out["idle_s"]
+    # per step: qwait 1 ms, make_batch 1.5 ms of its 2 (to_global inside it
+    # takes 0.5), metrics 1 ms, unspanned 1 ms; compute was all busy
+    assert idle["prefetch.qwait"] == pytest.approx(0.003, abs=1e-5)
+    assert idle["train.make_batch"] == pytest.approx(0.0045, abs=1e-5)
+    assert idle["batch.to_global"] == pytest.approx(0.0015, abs=1e-5)
+    assert idle["train.metrics"] == pytest.approx(0.003, abs=1e-5)
+    assert idle["train.step"] == pytest.approx(0.003, abs=1e-5)
+    assert "train.compute" not in idle
+    assert sum(idle.values()) == pytest.approx(0.027 - 0.012, abs=1e-5)
+    # no device plane (a CPU trace): anchors only, an empty split
+    cpu = obs_report.clock_split(records, _xplane(steps, off, []))
+    assert cpu["pairs"] == 3 and cpu["idle_s"] == {} and cpu["busy_s"] == 0
+
+
+def test_innermost_and_subtract_intervals():
+    segs = obs_report._innermost([(0, 10, "outer"), (2, 4, "inner"),
+                                  (4, 6, "next"), (7, 7, "empty")])
+    assert segs == [(0, 2, "outer"), (2, 4, "inner"), (4, 6, "next"),
+                    (6, 10, "outer")]
+    assert obs_report._subtract([(0, 10), (20, 30)],
+                                [[-5, 2], [4, 5], [9, 22], [25, 40]]) == [
+        (2, 4), (5, 9), (22, 25)]
+
+
+# ---------------------------------------------------------------------------
+# Trainer.run: spans that tile each step, and the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _train(tmp_path, *, prefetch_depth=2, steps=6):
+    """A short SOLAR-fed ``Trainer.run`` over a file store; returns the
+    trainer and the SHA-256 of the batches it trained."""
+    import hashlib
+
+    import jax
+
+    from repro.core.scheduler import SolarConfig
+    from repro.data import LoaderSpec, build_pipeline, create_synthetic_store
+    from repro.data.loaders import update_batch_digest
+    from repro.train.trainer import Trainer
+
+    path = tmp_path / "store.bin"
+    if not path.exists():
+        create_synthetic_store(str(path), num_samples=96, sample_shape=(4, 4),
+                               kind="random").close()
+    solar = SolarConfig(num_nodes=2, local_batch=4, buffer_size=16, seed=0)
+    ld = build_pipeline(LoaderSpec(
+        loader="solar", backend="binary", path=str(path), num_nodes=2,
+        local_batch=4, num_epochs=2, buffer_size=16, seed=0,
+        collect_data=True, solar=solar, prefetch_depth=prefetch_depth,
+    ))
+    digest = hashlib.sha256()
+
+    def make_batch(sb):
+        update_batch_digest(digest, sb)
+        x, w = sb.to_global(ld.capacity)
+        return {"x": x, "w": w}
+
+    step = jax.jit(lambda s, b: (s + 1, {"loss": (b["x"].sum(axis=(1, 2)) * b["w"]).sum(),
+                                         "rows": b["w"].sum()}))
+    t = Trainer(loader=ld, step_fn=step, state=np.int32(0), make_batch=make_batch,
+                prefetch_depth=prefetch_depth)
+    t.run(max_steps=steps)
+    return t, digest.hexdigest()
+
+
+def _by_kind(recs):
+    out: dict[str, list] = {}
+    for r in recs:
+        out.setdefault(obs_trace.kind_name(int(r["kind"])), []).append(r)
+    return out
+
+
+def test_trainer_spans_tile_each_step(tmp_path):
+    live = obs_trace.enable()
+    trainer, _ = _train(tmp_path)
+    recs, tids, _ = live.records()
+    main = threading.current_thread().name
+    on_main = _by_kind(r for r, tid in zip(recs, tids) if tid == main)
+    steps = on_main["train.step"]
+    assert [int(r["step"]) for r in steps] == list(range(6))
+    assert len(on_main["train.metrics"]) == 6, "one metric conversion a step"
+    for st in steps:
+        inside = [r for k in obs_report.TRAIN_STAGES.values() for r in on_main.get(k[0], [])
+                  if r["t0"] >= st["t0"] and r["t1"] <= st["t1"]]
+        covered = sum(r["t1"] - r["t0"] for r in inside)
+        assert covered >= 0.9 * (st["t1"] - st["t0"]), "children must tile train.step"
+    # breakdown's load_s is the wait for the batch plus make_batch, read off
+    # the same clock reads as the spans
+    load = sum(r["t1"] - r["t0"] for k in ("prefetch.qwait", "train.make_batch")
+               for r in on_main[k])
+    assert load <= trainer.load_time_s <= sum(st["t1"] - st["t0"] for st in steps)
+    compute = sum(r["t1"] - r["t0"] for r in on_main["train.compute"])
+    assert trainer.compute_time_s == pytest.approx(compute, rel=1e-9)
+    assert trainer.breakdown()["load_s"] > 0
+
+
+def test_to_global_and_read_wait_payloads(tmp_path):
+    live = obs_trace.enable()
+    _train(tmp_path, steps=4)
+    recs, tids, _ = live.records()
+    kinds = _by_kind(recs)
+    tg = kinds["batch.to_global"]
+    assert len(tg) == 4
+    # 2 nodes padded to the SPMD capacity; float32 rows of 4 x 4
+    rows = int(tg[0]["a"])
+    assert rows % 2 == 0 and rows >= 8
+    assert all(int(r["b"]) == int(r["a"]) * 16 * 4 for r in tg)
+    waits = [(r, tid) for r, tid in zip(recs, tids)
+             if obs_trace.kind_name(int(r["kind"])) == "prefetch.read_wait"]
+    assert len(waits) >= 4, "one wait per produced step"
+    assert all(int(r["a"]) == 2 for r, _ in waits), "one read task per node"
+    assert {tid for _, tid in waits} == {"solar-pipeline"}
+
+
+def test_untraced_trainer_records_nothing_and_trains_the_same(tmp_path, monkeypatch):
+    import jax
+
+    entered = []
+    real = jax.profiler.StepTraceAnnotation
+
+    def spy(name, **kw):
+        entered.append(kw["step_num"])
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", spy)
+    off, off_digest = _train(tmp_path)
+    assert entered == [], "an untraced run enters no profiler annotation"
+    assert not obs_trace.get().enabled
+    live = obs_trace.enable()
+    on, on_digest = _train(tmp_path)
+    assert entered == list(range(6))
+    assert len(live.records()[0]) > 0
+    assert off_digest == on_digest
+    assert off.metrics_history == on.metrics_history
+
+
+def test_trainer_steps_anchor_to_the_profiler_clock(tmp_path):
+    import jax
+
+    trace_dir = str(tmp_path / "trace")
+    live = obs_trace.enable()
+    jax.profiler.start_trace(trace_dir)
+    try:
+        _train(tmp_path, steps=8)
+    finally:
+        jax.profiler.stop_trace()
+    obs_trace.disable()
+    live.dump(trace_dir, rank=0)
+    rep = obs_report.analyze(trace_dir, trace_dir)
+    xp = rep["xplane"]
+    assert xp["pairs"] == 8
+    assert xp["spread_ns"] < 2_000_000
+    assert xp["idle_s"] == {}, "a CPU trace has no device plane"
+    assert obs_report.main([trace_dir, "--xplane", trace_dir, "--check"]) == 0
+
+
 # ---------------------------------------------------------------------------
 # The invariant that matters: traced == untraced, bit for bit
 # ---------------------------------------------------------------------------
