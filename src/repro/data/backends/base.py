@@ -110,6 +110,8 @@ class StorageBackend(Protocol):
 
     def read_ranges(self, ranges) -> list: ...
 
+    def physical_reads(self, ranges) -> list: ...
+
     def read_scattered(self, ids) -> np.ndarray: ...
 
     def reset_counters(self) -> None: ...
@@ -129,28 +131,41 @@ class CoalescingReadsMixin:
     def read_one(self, idx: int) -> np.ndarray:
         return self.read_range(idx, idx + 1)[0]
 
-    def read_ranges(self, ranges) -> list[np.ndarray]:
-        """Ranged reads with adjacency coalescing.
+    def physical_reads(self, ranges) -> list[tuple[int, int, int, int]]:
+        """The physical reads :meth:`read_ranges` makes for ``ranges``.
 
-        ``ranges`` is a sequence of ``(start, stop)`` pairs.  Consecutive
-        pairs whose spans touch (``prev_stop == next_start``) are merged into
-        one physical read and split back afterwards, so a run of adjacent
-        :class:`~repro.core.plan.ChunkRead`\\ s costs a single PFS call.
-        Returns one array per input range, in input order.
+        One ``(lo, hi, first, last)`` per read, in input order: samples
+        ``[lo, hi)`` in one call, covering input ranges ``first..last``.
+        Consecutive pairs whose spans touch (``prev_stop == next_start``)
+        share one read.  Cutting ``ranges`` only between two entries of this
+        list and reading the parts separately makes the same reads.
         """
         ranges = [(int(a), int(b)) for a, b in ranges]
-        out: list[np.ndarray | None] = [None] * len(ranges)
+        out = []
         i = 0
         while i < len(ranges):
             j = i
             while j + 1 < len(ranges) and ranges[j + 1][0] == ranges[j][1]:
                 j += 1
-            lo, hi = ranges[i][0], ranges[j][1]
+            out.append((ranges[i][0], ranges[j][1], i, j))
+            i = j + 1
+        return out
+
+    def read_ranges(self, ranges) -> list[np.ndarray]:
+        """Ranged reads with adjacency coalescing.
+
+        ``ranges`` is a sequence of ``(start, stop)`` pairs, read as
+        :meth:`physical_reads` plans them and split back afterwards, so a
+        run of adjacent :class:`~repro.core.plan.ChunkRead`\\ s costs a
+        single PFS call.  Returns one array per input range, in input order.
+        """
+        ranges = [(int(a), int(b)) for a, b in ranges]
+        out: list[np.ndarray | None] = [None] * len(ranges)
+        for lo, hi, i, j in self.physical_reads(ranges):
             arr = self.read_range(lo, hi)
             for k in range(i, j + 1):
                 a, b = ranges[k]
                 out[k] = arr[a - lo : b - lo]
-            i = j + 1
         return out  # type: ignore[return-value]
 
     def read_scattered(self, ids) -> np.ndarray:

@@ -6,14 +6,15 @@ the runtime issues a few *large* reads aligned to the HDF5 chunk grid, each
 covering whole chunks, and slices the wanted samples back out.  This backend
 implements exactly that:
 
-  * ``read_ranges`` first coalesces adjacent logical ranges (like every
-    backend), then rounds each merged span outward to HDF5 chunk boundaries
-    and merges spans whose *aligned* windows touch — so a step's ChunkReads
-    that land in the same chunks cost one h5py call, and the HDF5 chunk
-    cache is never re-read for partially-consumed chunks.  ``bytes_read``
-    counts the aligned span (chunk waste included), mirroring the paper's
-    numPFS-with-waste accounting.  Set ``align_chunks=False`` for the naive
-    exact-span behaviour (the benchmark's ablation baseline).
+  * ``read_ranges`` (through ``physical_reads``) first coalesces adjacent
+    logical ranges (like every backend), then rounds each merged span
+    outward to HDF5 chunk boundaries and merges spans whose *aligned*
+    windows touch — so a step's ChunkReads that land in the same chunks
+    cost one h5py call, and the HDF5 chunk cache is never re-read for
+    partially-consumed chunks.  ``bytes_read`` counts the aligned span
+    (chunk waste included), mirroring the paper's numPFS-with-waste
+    accounting.  Set ``align_chunks=False`` for the naive exact-span
+    behaviour (the benchmark's ablation baseline).
   * the HDF5 chunk-cache size is a knob (``rdcc_nbytes``/``rdcc_nslots``,
     passed straight to :class:`h5py.File`), and
   * ``simulated_latency_s`` injects per-call latency for PFS emulation,
@@ -160,23 +161,23 @@ class Hdf5Backend(BaseBackend):
         finally:
             self._release_handle(handle)
 
-    def read_ranges(self, ranges) -> list[np.ndarray]:
-        """Aggregated chunk-aligned ranged reads.
+    def physical_reads(self, ranges) -> list[tuple[int, int, int, int]]:
+        """Aggregated chunk-aligned reads.
 
         Adjacent-touching input ranges are merged (as everywhere), then each
         merged span is rounded outward to the HDF5 chunk grid; consecutive
         spans whose aligned windows touch or overlap collapse into a single
-        dataset read covering whole chunks.  The wanted sub-ranges are sliced
-        back out, preserving the one-array-per-input-range contract.
+        dataset read covering whole chunks, from which :meth:`read_ranges`
+        slices the wanted sub-ranges back out.
         """
         if not self.align_chunks or self.chunk_samples <= 0:
-            return super().read_ranges(ranges)
+            return super().physical_reads(ranges)
         c = self.chunk_samples
         ranges = [(int(a), int(b)) for a, b in ranges]
         for a, b in ranges:
             if not 0 <= a < b <= self.num_samples:
                 raise IndexError((a, b, self.num_samples))
-        out: list[np.ndarray | None] = [None] * len(ranges)
+        out = []
         i = 0
         while i < len(ranges):
             lo, hi = ranges[i]
@@ -189,12 +190,9 @@ class Hdf5Backend(BaseBackend):
                     break  # unsorted, or next aligned window is disjoint
                 ahi = max(ahi, min(-(-nhi // c) * c, self.num_samples))
                 j += 1
-            arr = self._pread(alo, ahi)  # one aggregated h5py call
-            for k in range(i, j + 1):
-                a, b = ranges[k]
-                out[k] = arr[a - alo : b - alo]
+            out.append((alo, ahi, i, j))  # one aggregated h5py call
             i = j + 1
-        return out  # type: ignore[return-value]
+        return out
 
     # -- construction ----------------------------------------------------------
 

@@ -10,9 +10,11 @@ does exactly that:
     refactor that is *every* strategy, baselines included): a pipeline
     thread walks the plan ``depth`` steps ahead of the consumer and submits
     every node-step's coalesced :class:`~repro.core.plan.ChunkRead` batch to
-    a thread pool, so PFS calls for *different* nodes and *future* steps are
-    in flight concurrently; batches are then assembled strictly in plan
-    order (buffer-mirror deltas are order-dependent) and handed to the
+    a thread pool, cut into up to ``num_workers`` read tasks at the store's
+    physical-read boundaries (:func:`read_tasks`), so PFS calls within one
+    step, for *different* nodes and for *future* steps are in flight
+    concurrently, oldest step first; batches are then assembled strictly in
+    plan order (buffer-mirror deltas are order-dependent) and handed to the
     consumer through a bounded queue.  A step's planned peer fetches
     (DESIGN.md §6) are gathered at assembly time — the only point where the
     buffer mirrors are in the start-of-step state the plan priced —
@@ -50,9 +52,44 @@ from concurrent.futures import ThreadPoolExecutor
 
 from repro.obs import trace as obs_trace
 
-__all__ = ["PrefetchExecutor", "WindowReadAhead"]
+__all__ = ["PrefetchExecutor", "WindowReadAhead", "read_tasks"]
 
 _SENTINEL = object()
+
+
+def read_tasks(store, ranges: list, max_tasks: int) -> list[list]:
+    """Cut one node-step's ``ranges`` into at most ``max_tasks`` read tasks.
+
+    Each task is a run of ``ranges`` in plan order.  Cuts fall only between
+    two of the store's :meth:`physical_reads`, so the tasks read separately
+    make exactly the reads one ``read_ranges(ranges)`` would; the runs are
+    balanced by the samples those reads move.  Ranges the store reads in
+    one call (none, one, or all merged) stay one task.
+    """
+    reads = store.physical_reads(ranges)
+    k = min(len(reads), max_tasks)
+    if k <= 1:
+        return [ranges]
+    prefix = [0]
+    for lo, hi, _, _ in reads:
+        prefix.append(prefix[-1] + hi - lo)
+    # cuts[g] = the index of the first physical read of task g
+    cuts = [0]
+    for g in range(1, k):
+        target = prefix[-1] * g / k
+        cuts.append(min(range(cuts[-1] + 1, len(reads) - (k - g) + 1),
+                        key=lambda b: abs(prefix[b] - target)))
+    firsts = [reads[b][2] for b in cuts] + [len(ranges)]
+    return [ranges[a:b] for a, b in zip(firsts, firsts[1:])]
+
+
+def _read_task(store, ranges, step: int) -> list:
+    """One read task on a pool worker: ``store.read_ranges`` under a span."""
+    tr = obs_trace.get()
+    t0 = tr.t()
+    arrays = store.read_ranges(ranges)
+    tr.rec(obs_trace.PREFETCH_READ_TASK, t0, a=len(ranges), b=step)
+    return arrays
 
 
 class WindowReadAhead:
@@ -262,7 +299,8 @@ class PrefetchExecutor:
         steps = iter(ld.plan_steps())
         steps_ready = getattr(ld, "stream_steps_ready", None)
         pulled = 0
-        #: (EpochPlan, StepPlan, per-node futures) issued but not yet assembled.
+        #: (EpochPlan, StepPlan, per-node read-task futures) issued but not
+        #: yet assembled.
         pending: deque = deque()
         exhausted = False
         while not run.cancel.is_set():
@@ -285,10 +323,14 @@ class PrefetchExecutor:
                 futs = None
                 if collect:
                     futs = [
-                        run.pool.submit(
-                            ld.store.read_ranges,
-                            [(c.start, c.stop) for c in npn.chunks],
-                        )
+                        [
+                            run.pool.submit(_read_task, ld.store, part, sp.step)
+                            for part in read_tasks(
+                                ld.store,
+                                [(c.start, c.stop) for c in npn.chunks],
+                                self.num_workers,
+                            )
+                        ]
                         for npn in sp.nodes
                     ]
                 pending.append((ep, sp, futs))
@@ -302,8 +344,12 @@ class PrefetchExecutor:
             chunk_arrays = None
             if futs:
                 t0 = tr.t()
-                chunk_arrays = [f.result() for f in futs]
-                tr.rec(obs_trace.PREFETCH_READ_WAIT, t0, a=len(futs))
+                chunk_arrays = [
+                    [arr for f in node_futs for arr in f.result()]
+                    for node_futs in futs
+                ]
+                tr.rec(obs_trace.PREFETCH_READ_WAIT, t0,
+                       a=sum(len(node_futs) for node_futs in futs))
             if gather_peers is not None:
                 sb = ld.execute_step(
                     ep, sp, chunk_arrays=chunk_arrays, peer_arrays=peer_arrays
@@ -314,5 +360,6 @@ class PrefetchExecutor:
                 break
         # Cancelled: wait out in-flight reads so pool shutdown is clean.
         for _, _, futs in pending:
-            for f in futs or ():
-                f.cancel()
+            for node_futs in futs or ():
+                for f in node_futs:
+                    f.cancel()

@@ -105,6 +105,7 @@ TRAIN_METRICS = kind_id("train.metrics")        # metrics -> Python floats
 TRAIN_CHECKPOINT = kind_id("train.checkpoint")  # Trainer's checkpoint save
 BATCH_TO_GLOBAL = kind_id("batch.to_global")    # a=rows, b=bytes returned
 PREFETCH_READ_WAIT = kind_id("prefetch.read_wait")  # a=read tasks waited on
+PREFETCH_READ_TASK = kind_id("prefetch.read_task")  # a=ranges, b=plan step
 
 _NULL_CTX = nullcontext()
 

@@ -530,8 +530,34 @@ def test_to_global_and_read_wait_payloads(tmp_path):
     waits = [(r, tid) for r, tid in zip(recs, tids)
              if obs_trace.kind_name(int(r["kind"])) == "prefetch.read_wait"]
     assert len(waits) >= 4, "one wait per produced step"
-    assert all(int(r["a"]) == 2 for r, _ in waits), "one read task per node"
+    # 2 nodes, each step's reads cut into 1..num_workers (4) tasks a node
+    assert all(2 <= int(r["a"]) <= 2 * 4 for r, _ in waits)
     assert {tid for _, tid in waits} == {"solar-pipeline"}
+    tasks = [(r, tid) for r, tid in zip(recs, tids)
+             if obs_trace.kind_name(int(r["kind"])) == "prefetch.read_task"]
+    assert sum(int(r["a"]) for r, _ in waits) <= len(tasks), "each task waited on once"
+    assert {tid.rsplit("_", 1)[0] for _, tid in tasks} == {"solar-io"}
+
+
+def test_read_task_spans_leave_the_train_block_unchanged(tmp_path):
+    """``prefetch.read_task`` is recorded on the I/O pool's threads: the
+    report's ``train`` block and ``coverage`` tile the loop's thread only."""
+    from repro.obs import report
+
+    live = obs_trace.enable()
+    _train(tmp_path, steps=4)
+    obs_trace.disable()
+    with_dir, without_dir = tmp_path / "with", tmp_path / "without"
+    live.dump(str(with_dir))
+    without_dir.mkdir()
+    lines = (with_dir / "trace-rank0.jsonl").read_text().splitlines()
+    kept = [ln for ln in lines if '"prefetch.read_task"' not in ln]
+    assert len(kept) < len(lines), "the run recorded read tasks"
+    (without_dir / "trace-rank0.jsonl").write_text("\n".join(kept) + "\n")
+    a = report.analyze(str(with_dir))["ranks"]["0"]
+    b = report.analyze(str(without_dir))["ranks"]["0"]
+    assert a["train"] == b["train"] and a["train"]["steps"] == 4
+    assert a["coverage"] == b["coverage"]
 
 
 def test_untraced_trainer_records_nothing_and_trains_the_same(tmp_path, monkeypatch):
