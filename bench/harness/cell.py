@@ -29,10 +29,10 @@ from pathlib import Path
 
 import numpy as np
 
-from bench.harness import catalog, checks, data, devtrace, flops, pfs, reference
+from bench.harness import catalog, checks, data, devtrace, pfs, reference
 
-__all__ = ["NoChip", "Run", "WindowClosed", "loader_spec", "program_step", "reference_batches",
-           "run_cell"]
+__all__ = ["NoChip", "Run", "WindowClosed", "loader_spec", "program_config", "program_step",
+           "reference_batches", "run_cell"]
 
 
 class NoChip(RuntimeError):
@@ -143,14 +143,18 @@ class _TimedStep:
         return new_state, metrics
 
 
-def _surrogate_config(cfg):
+def _frozen(value):
+    return tuple(_frozen(v) for v in value) if isinstance(value, list) else value
+
+
+def program_config(cfg):
+    """The program's ``SurrogateConfig`` of the configuration: each of the
+    dataclass's fields read from the key of its name (lists as tuples), so a
+    field the program adds is read from the file as it stands."""
     from repro.configs.surrogates import SurrogateConfig
 
-    return SurrogateConfig(
-        name=cfg["name"], kind=cfg["kind"],
-        input_shape=tuple(cfg["input_shape"]), output_shape=tuple(cfg["output_shape"]),
-        base_channels=cfg["base_channels"], depth=cfg["depth"],
-    )
+    return SurrogateConfig(**{f.name: _frozen(cfg[f.name])
+                              for f in dataclasses.fields(SurrogateConfig) if f.name in cfg})
 
 
 def program_step(cfg):
@@ -162,7 +166,7 @@ def program_step(cfg):
     from repro.optim.adamw import AdamWConfig
     from repro.train import step as train_step
 
-    scfg = _surrogate_config(cfg)
+    scfg = program_config(cfg)
     opt = AdamWConfig(**cfg["optimizer"])
     step_cfg = types.SimpleNamespace(grad_accum=1, grad_accum_dtype="float32")
     return jax.jit(train_step.make_train_step(
@@ -171,7 +175,8 @@ def program_step(cfg):
 
 def _split(cfg, record_rows, ids, table):
     """A batch's (x, y) from its records: the input channels and the target
-    channels of a record, or the record and the seeded target table."""
+    channels of a record, or the record and the seeded target table. The
+    records keep their dtype; the network's forward pass transforms them."""
     cin = cfg["input_shape"][-1]
     if table is None:
         return record_rows[..., :cin], record_rows[..., cin:]
@@ -203,7 +208,8 @@ def reference_batches(cfg, seed: int, id_lists, table) -> list:
     """``(x, y)`` of the real rows of each step's ids, made from the seed."""
     out = []
     for ids in id_lists:
-        x, y = _split(cfg, data.records(seed, ids, cfg["record_shape"]), ids, table)
+        x, y = _split(cfg, data.records(seed, ids, cfg["record_shape"], data.record_dtype(cfg)),
+                      ids, table)
         out.append((x, y[: len(ids)]))
     return out
 
@@ -225,7 +231,7 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
     """Run the cell once; returns the result line's object and the check lines."""
     root = Path(root)
     cell = catalog.load_cell(root, name)
-    cfg, wl = cell.config, cell.workload
+    cfg, wl, net = cell.config, cell.workload, cell.network
 
     import jax
 
@@ -250,8 +256,9 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
     setup = {"start_s": time.perf_counter() - t_start}
     if trace:
         obs_trace.enable(capacity=1 << 18)
-    scfg = _surrogate_config(cfg)
+    scfg = program_config(cfg)
     record_shape = tuple(cfg["record_shape"])
+    dtype = data.record_dtype(cfg)
     n = int(cfg["num_samples"])
     table = (data.targets(seed, n, math.prod(cfg["output_shape"]))
              if cfg["targets"] == "seeded" else None)
@@ -260,7 +267,7 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
         # -- data set, written through the cell's backend --------------------
         t = time.perf_counter()
         path = os.path.join(tmp, f"{cfg['name']}.{wl['backend']}")
-        records = data.generate(seed, n, record_shape)
+        records = data.generate(seed, n, record_shape, dtype=dtype)
         create_store(path, wl["backend"], data=records, **wl["store_options"]).close()
         del records
         link = pfs.PfsLink(wl["pfs"]["latency_s"], wl["pfs"]["bandwidth_bytes_per_s"])
@@ -283,12 +290,12 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
         t = time.perf_counter()
         want = jax.eval_shape(lambda: cnn.init_surrogate(jax.random.PRNGKey(0), scfg))
         key = reference.jax_key(seed)
-        got = jax.eval_shape(lambda: reference.init_params(key, cfg))
+        got = jax.eval_shape(lambda: net.init_params(key, cfg))
         if jax.tree_util.tree_structure(want) != jax.tree_util.tree_structure(got) or [
                 a.shape for a in jax.tree_util.tree_leaves(want)] != [
                 a.shape for a in jax.tree_util.tree_leaves(got)]:
             raise RuntimeError("the program's parameter tree differs from the reference's")
-        params = jax.jit(lambda k: reference.init_params(k, cfg))(key)
+        params = jax.jit(lambda k: net.init_params(k, cfg))(key)
         params0 = jax.device_get(params)
         step, opt = program_step(cfg)
         state = train_step.init_train_state(params, opt)
@@ -382,7 +389,7 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
                 np.arange(n))), 0)
         bad_rows = bad_weights = 0
         for ids, x, y, w in first + kept:
-            xr, yr = _split(cfg, data.records(seed, ids, record_shape), ids, table)
+            xr, yr = _split(cfg, data.records(seed, ids, record_shape, dtype), ids, table)
             k = len(ids)
             bad_rows += int(np.sum(np.any(x[:k] != xr, axis=tuple(range(1, x.ndim)))
                                    | np.any(y[:k] != yr[:k], axis=tuple(range(1, y.ndim)))))
@@ -393,7 +400,8 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
             raise RuntimeError("fewer than three steps ran")
         batches = reference_batches(cfg, seed, [ids for _, _, ids in planned[:3]], table)
         with jax.default_matmul_precision("highest"):
-            ref_losses, ref_g, ref_p3 = reference.train_steps(params0, batches, cfg, capacity)
+            ref_losses, ref_g, ref_p3 = reference.train_steps(net, params0, batches, cfg,
+                                                              capacity)
         b1 = cfg["optimizer"]["b1"]
         g1 = [a / (1 - b1) for a in reference.leaves(timed.mu1)]
         g1_ref = reference.leaves(ref_g)
@@ -401,7 +409,7 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
         d3 = [a - b for a, b in zip(reference.leaves(timed.params3), p0)]
         d3_ref = [a - b for a, b in zip(reference.leaves(ref_p3), p0)]
         keep = checks.steady_leaves(g1_ref)
-        last = reference.last_layer(cfg)
+        last = net.last_layer(cfg)
         gg, ug = checks.leaf_gaps(g1, g1_ref), checks.leaf_gaps(d3, d3_ref)
         ck.add("loss_gap_steps_1_3", checks.loss_gap(losses[:3], ref_losses),
                cfg["limits"]["loss_gap"])
@@ -430,7 +438,7 @@ def run_cell(root, name: str, *, seed: int, seconds: float, trace: bool,
                  for j in range(len(done))]
         run = Run(setup_s=(timed.t_open or t_close) - t_start,
                   t_open=timed.t_open or t_close, t_close=t_close, steps=steps,
-                  pfs_reads=list(link.reads), flops_per_sample=flops.train_flops_per_sample(cfg),
+                  pfs_reads=list(link.reads), flops_per_sample=net.train_flops_per_sample(cfg),
                   peak_flops=peak, spans=spans, device=reduced)
         if len(link.reads) != store.read_calls:
             raise RuntimeError(f"the PFS stand-in charged {len(link.reads)} reads, "

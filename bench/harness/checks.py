@@ -18,11 +18,11 @@ there, and Adam's sign-like first steps carry that into the changes of
 elements whose gradient is near zero (PERF.md gives the readings).
 
 A limit with ``_last_layer_`` in its name holds only the leaves of the layer
-next to the loss (``reference.last_layer``). No leaky ReLU lies between them
-and the loss, so their gradient has no kink to fall on either side of, and
-in a sound run it differs from the reference's by rounding alone; deeper
-leaves differ by as much as a lower matmul precision moves them wherever a
-pre-activation lies within rounding of a kink.
+next to the loss (the network module's ``last_layer``). No leaky ReLU lies
+between them and the loss, so their gradient has no kink to fall on either
+side of, and in a sound run it differs from the reference's by rounding
+alone; deeper leaves differ by as much as a lower matmul precision moves
+them wherever a pre-activation lies within rounding of a kink.
 """
 from __future__ import annotations
 

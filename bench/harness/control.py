@@ -27,7 +27,7 @@ from bench.harness.cell import loader_spec, program_step, reference_batches
 __all__ = ["readings"]
 
 
-def _compare(cfg, params0, ref, other) -> dict:
+def _compare(net, cfg, params0, ref, other) -> dict:
     losses, g, p3 = other
     ref_losses, ref_g, ref_p3 = ref
     p0 = reference.leaves(params0)
@@ -42,7 +42,7 @@ def _compare(cfg, params0, ref, other) -> dict:
         if name != "loss_gap":
             gaps = out["grad_leaf_gaps"] if name.startswith("grad") else out["update_leaf_gaps"]
             out[name] = checks.held_gap(name, gaps, None if name.startswith("grad") else keep,
-                                        reference.last_layer(cfg))
+                                        net.last_layer(cfg))
     return out
 
 
@@ -81,24 +81,24 @@ def readings(cell, seed: int) -> dict:
 
     from repro.data import plan
 
-    cfg, wl = cell.config, cell.workload
+    cfg, wl, net = cell.config, cell.workload, cell.network
     n = int(cfg["num_samples"])
     mine = plan(loader_spec(cfg, wl), num_samples=n).for_node(wl["rank"])
     table = (data.targets(seed, n, math.prod(cfg["output_shape"]))
              if cfg["targets"] == "seeded" else None)
     batches = reference_batches(
         cfg, seed, [sp.nodes[0].sample_ids for ep in mine.epochs for sp in ep.steps][:3], table)
-    params0 = jax.device_get(jax.jit(lambda k: reference.init_params(k, cfg))(
+    params0 = jax.device_get(jax.jit(lambda k: net.init_params(k, cfg))(
         reference.jax_key(seed)))
     rows = mine.capacity
     with jax.default_matmul_precision("highest"):
-        ref = reference.train_steps(params0, batches, cfg, rows)
+        ref = reference.train_steps(net, params0, batches, cfg, rows)
         half = reference.train_steps(
-            params0, [(x[: max(1, len(x) // 2)], y[: max(1, len(y) // 2)])
-                      for x, y in batches], cfg, rows)
+            net, params0, [(x[: max(1, len(x) // 2)], y[: max(1, len(y) // 2)])
+                           for x, y in batches], cfg, rows)
     with jax.default_matmul_precision(cfg["control_precision"]):
-        control = reference.train_steps(params0, batches, cfg, rows)
-    return {"program": _compare(cfg, params0, ref, _program(cfg, params0, batches, rows)),
-            "control": _compare(cfg, params0, ref, control),
-            "half_batch": _compare(cfg, params0, ref, half),
+        control = reference.train_steps(net, params0, batches, cfg, rows)
+    return {"program": _compare(net, cfg, params0, ref, _program(cfg, params0, batches, rows)),
+            "control": _compare(net, cfg, params0, ref, control),
+            "half_batch": _compare(net, cfg, params0, ref, half),
             "rows": [len(x) for x, _ in batches]}

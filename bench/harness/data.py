@@ -1,18 +1,23 @@
 """The data set of a run, made from ``--seed``.
 
-Records are standard-normal float32, drawn in blocks: block ``b`` comes from
-a Philox stream keyed by ``(seed, b)``, so any record can be made again
-without the others (the check reads records back this way, with no loader),
-and blocks are drawn in parallel threads. Every seed gives the same sizes;
-only the values differ.
+Records are drawn in blocks: block ``b`` comes from a Philox stream keyed by
+``(seed, b)``, so any record can be made again without the others (the check
+reads records back this way, with no loader), and blocks are drawn in
+parallel threads. Every seed gives the same sizes; only the values differ.
+
+A configuration's optional ``record_dtype`` sets what a record holds:
+``"float32"`` (the default) standard-normal values; ``"int16"`` counts
+``k >= 0`` with probability ``2**-(k+1)`` (mean 1), the floor of an
+exponential draw over ln 2, as a data set of particle counts stores them.
 """
 from __future__ import annotations
 
+import math
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-__all__ = ["block_rows", "generate", "records", "targets"]
+__all__ = ["block_rows", "generate", "record_dtype", "records", "targets"]
 
 #: about this many bytes per block; a record larger than that is one block.
 _BLOCK_BYTES = 8 << 20
@@ -20,41 +25,62 @@ _BLOCK_BYTES = 8 << 20
 _TARGET_STREAM = 1 << 63
 
 
-def block_rows(record_shape) -> int:
-    nbytes = 4 * int(np.prod(record_shape))
+def _normal(rng, shape) -> np.ndarray:
+    return rng.standard_normal(shape, dtype=np.float32)
+
+
+def _counts(rng, shape) -> np.ndarray:
+    e = rng.standard_exponential(shape, dtype=np.float32)
+    return np.floor(e / np.float32(math.log(2))).astype(np.int16)
+
+
+_DRAW = {"float32": _normal, "int16": _counts}
+
+
+def record_dtype(cfg) -> str:
+    """The configuration's ``record_dtype``: ``"float32"`` or ``"int16"``."""
+    name = cfg.get("record_dtype", "float32")
+    if name not in _DRAW:
+        raise ValueError(f"record_dtype {name!r} is not one of {sorted(_DRAW)}")
+    return name
+
+
+def block_rows(record_shape, dtype: str = "float32") -> int:
+    nbytes = np.dtype(dtype).itemsize * int(np.prod(record_shape))
     return max(1, _BLOCK_BYTES // nbytes)
 
 
-def _block(seed: int, b: int, n: int, record_shape) -> np.ndarray:
+def _block(seed: int, b: int, n: int, record_shape, dtype: str) -> np.ndarray:
     rng = np.random.Generator(np.random.Philox(key=[seed, b]))
-    return rng.standard_normal((n,) + tuple(record_shape), dtype=np.float32)
+    return _DRAW[dtype](rng, (n,) + tuple(record_shape))
 
 
-def generate(seed: int, num_samples: int, record_shape, threads: int = 8) -> np.ndarray:
-    """All ``num_samples`` records, ``[num_samples, *record_shape]`` float32."""
-    rows = block_rows(record_shape)
-    out = np.empty((num_samples,) + tuple(record_shape), np.float32)
+def generate(seed: int, num_samples: int, record_shape, threads: int = 8,
+             dtype: str = "float32") -> np.ndarray:
+    """All ``num_samples`` records, ``[num_samples, *record_shape]``."""
+    rows = block_rows(record_shape, dtype)
+    out = np.empty((num_samples,) + tuple(record_shape), dtype)
 
     def fill(b: int) -> None:
         lo = b * rows
         hi = min(lo + rows, num_samples)
-        out[lo:hi] = _block(seed, b, hi - lo, record_shape)
+        out[lo:hi] = _block(seed, b, hi - lo, record_shape, dtype)
 
     with ThreadPoolExecutor(threads) as pool:
         list(pool.map(fill, range(-(-num_samples // rows))))
     return out
 
 
-def records(seed: int, ids, record_shape) -> np.ndarray:
+def records(seed: int, ids, record_shape, dtype: str = "float32") -> np.ndarray:
     """Records ``ids`` (in that order), made again from the seed."""
     ids = np.asarray(ids, np.int64)
-    rows = block_rows(record_shape)
-    out = np.empty((ids.size,) + tuple(record_shape), np.float32)
+    rows = block_rows(record_shape, dtype)
+    out = np.empty((ids.size,) + tuple(record_shape), dtype)
     for b in np.unique(ids // rows):
         lo = int(b) * rows
         sel = np.flatnonzero(ids // rows == b)
         n = int(ids[sel].max()) - lo + 1
-        out[sel] = _block(seed, int(b), n, record_shape)[ids[sel] - lo]
+        out[sel] = _block(seed, int(b), n, record_shape, dtype)[ids[sel] - lo]
     return out
 
 

@@ -1,5 +1,6 @@
 """Model FLOPs of the real samples trained per second, over the chip's bf16
-peak (bench/peaks.json). FLOPs per sample: bench/harness/flops.py."""
+peak (bench/peaks.json). FLOPs per sample: the configuration's network
+module (bench/networks/)."""
 
 
 def read(run):

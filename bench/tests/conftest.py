@@ -15,21 +15,30 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import pytest  # noqa: E402
 
-#: small stand-ins of the two configurations: same kinds and layouts.
-TINY = {
-    "ptychonn_repo": dict(input_shape=[16, 16, 1], output_shape=[16, 16, 2], base_channels=8,
-                     depth=2, record_shape=[16, 16, 3], local_batch=8, num_samples=512),
-    "cosmoflow_repo": dict(input_shape=[16, 16, 16, 4], output_shape=[4], base_channels=8,
-                      depth=2, record_shape=[16, 16, 16, 4], local_batch=2, num_samples=48),
-}
+#: the benchmark's configurations, name -> file, as BENCHMARK.json lists them.
+with open(ROOT / "BENCHMARK.json") as _f:
+    CONFIG_FILES = {c["name"]: ROOT / c["file"] for c in json.load(_f)["configs"]}
+
+
+def config(name: str) -> dict:
+    with open(CONFIG_FILES[name]) as f:
+        return json.load(f)
 
 
 def tiny_config(name: str) -> dict:
-    with open(ROOT / "bench" / "configs" / f"{name}.json") as f:
-        cfg = json.load(f)
-    cfg.update(TINY[name], name=f"tiny_{cfg['kind']}")
+    """The configuration's small stand-in for the CPU: its ``tiny`` block
+    over the file, the same network and layout at small sizes."""
+    cfg = config(name)
+    cfg.update(cfg.pop("tiny"))
     cfg.pop("parameters", None)
     return cfg
+
+
+def per_layer_off_device() -> set:
+    """The per-layer metrics of BENCHMARK.json that a run without a device
+    trace (on the CPU) reads: all but those taken from the device trace."""
+    with open(ROOT / "BENCHMARK.json") as f:
+        return {m["name"] for m in json.load(f)["per_layer"] if m["source"] != "device_trace"}
 
 
 @pytest.fixture()
@@ -38,6 +47,8 @@ def tiny_root(tmp_path):
     cells, tiny_ptychonn.pfs and tiny_cosmoflow.cached, for the CPU."""
     bench = tmp_path / "bench"
     shutil.copytree(ROOT / "bench" / "metrics", bench / "metrics")
+    shutil.copytree(ROOT / "bench" / "networks", bench / "networks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (bench / "configs").mkdir()
     (bench / "workloads").mkdir()
     with open(ROOT / "BENCHMARK.json") as f:

@@ -4,6 +4,7 @@ from pathlib import Path
 
 import pytest
 
+import conftest
 from bench.harness import catalog
 from bench.harness.cell import Run
 
@@ -50,9 +51,7 @@ def test_traced_run_reads_the_new_spans(tiny_root):
                          trace=True, t_start=time.perf_counter(), require_tpu=False)
     assert result["correct"]
     # the CPU has no device plane: no idle share, no busy time
-    assert set(result["metrics"]) == {"input_wait_ms", "buffer_hit_rate", "pfs_reads_per_step",
-                                      "assemble_ms", "step_mfu", "metrics_fetch_ms",
-                                      "to_global_ms", "read_wait_ms"}
+    assert set(result["metrics"]) == conftest.per_layer_off_device()
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert 0 < m["to_global_ms"] < m["assemble_ms"], "to_global is a part of make_batch"
     assert m["metrics_fetch_ms"] > 0 and m["read_wait_ms"] >= 0
